@@ -268,6 +268,9 @@ def run(full: bool = False, smoke: bool = False, dp: int = 1,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     import argparse
 
     ap = argparse.ArgumentParser()

@@ -168,6 +168,9 @@ def run(smoke: bool = False, json_path: str = "BENCH_serve.json") -> int:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="two-point sweep with short windows (CI)")

@@ -52,4 +52,7 @@ def run(full: bool = False, ks=(5, 50)):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     run()

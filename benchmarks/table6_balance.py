@@ -39,4 +39,7 @@ def run(full: bool = False, k: int = 5):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     run()

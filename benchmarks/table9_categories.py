@@ -56,4 +56,7 @@ def run(full: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     run()

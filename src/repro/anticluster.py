@@ -914,12 +914,14 @@ class AnticlusterEngine:
     _donation_advisory_silenced = False
 
     def __init__(self, spec: AnticlusterSpec | None = None, **overrides):
-        # Engines always request state-buffer donation; backends that cannot
-        # honor it (CPU) emit an advisory per executable.  Install the filter
-        # once, process-wide -- a per-call warnings.catch_warnings() would
-        # mutate global filter state on every repartition and race under
-        # threaded serving.
-        if not AnticlusterEngine._donation_advisory_silenced:
+        # Engines always request state-buffer donation; the CPU backend
+        # cannot honor it and emits an advisory per executable.  Install the
+        # filter once, process-wide -- a per-call warnings.catch_warnings()
+        # would mutate global filter state on every repartition and race
+        # under threaded serving.  Only on CPU: on an accelerator the
+        # advisory means a donation really failed, and stays loud.
+        if not AnticlusterEngine._donation_advisory_silenced \
+                and jax.default_backend() == "cpu":
             warnings.filterwarnings(
                 "ignore", message="Some donated buffers were not usable")
             AnticlusterEngine._donation_advisory_silenced = True

@@ -41,7 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.assignment import AuctionConfig, get_solver
-from repro.kernels.ops import gather_rows
+from repro.kernels.ops import row_gatherer
 
 _MASK_COST = -1e9  # categorical upper-bound mask (paper 4.3)
 
@@ -469,7 +469,7 @@ def aba_stream(
     is no concatenated/permuted dataset copy anywhere (chunks are dynamic
     slices; sentinel rows are clamped gathers masked by ``is_real``).  On
     TPU the per-chunk gather runs through the double-buffered DMA kernel
-    (``repro.kernels.ops.gather_rows``) so the next chunk's row movement
+    (``repro.kernels.ops.row_gatherer``) so the next chunk's row movement
     overlaps the current chunk's batch solves.  With a ``factored`` solver
     (e.g. "auction_fused") each batch's LAP is matrix-free on top: the
     (k, k) value matrix is never built either (`bid_top2` streams column
@@ -740,12 +740,14 @@ def aba_stream(
     p_init = (jnp.zeros((1, k), jnp.float32) if prices_in is None
               else prices_in)
 
+    take_rows = row_gatherer(xf)  # lays x out for the gather once
+
     def chunk_step(carry, inp):
         cents, counts, ccat, p_last = carry
         idx_c, real_c = inp                      # (cpb, k)
         idx_g = jnp.minimum(idx_c, n - 1)
         # ONE (chunk, d) gather; double-buffered DMA kernel on TPU
-        xc = gather_rows(xf, idx_g.reshape(-1)).reshape(cpb, k, d)
+        xc = take_rows(idx_g.reshape(-1)).reshape(cpb, k, d)
         if categories is not None:
             xs = (xc, real_c, codes_i[idx_g])    # + (cpb, k, A) code gather
         else:

@@ -39,9 +39,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from repro.compat import shard_map
 
 from repro.core.assignment import AuctionConfig
 from repro.core.hierarchical import (default_plan, hierarchical_core,

@@ -23,7 +23,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import TPUCompilerParams
+
+def dot_t(a, b):
+    """``a @ b.T`` on the MXU at full f32 precision.
+
+    Mosaic runs an f32 dot without a stated precision as one bf16 pass:
+    on a v5e that put the bidding values about 5e-4 of their scale away
+    from the f32 reference.  Every kernel's dot goes through here.
+    """
+    return jax.lax.dot_general(
+        a, b, dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
 
 def _cdist_kernel(x_ref, c_ref, xn_ref, cn_ref, o_ref, acc_ref, *, k_steps):
@@ -33,16 +44,12 @@ def _cdist_kernel(x_ref, c_ref, xn_ref, cn_ref, o_ref, acc_ref, *, k_steps):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[...], c_ref[...],
-        dimension_numbers=(((1,), (1,)), ((), ())),  # (bm, bk) x (bn, bk)^T
-        preferred_element_type=jnp.float32)
+    acc_ref[...] += dot_t(x_ref[...], c_ref[...])  # (bm, bk) x (bn, bk)^T
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _finish():
-        o_ref[...] = (
-            xn_ref[...][:, None] - 2.0 * acc_ref[...] + cn_ref[...][None, :]
-        ).astype(o_ref.dtype)
+        o_ref[...] = (xn_ref[...] - 2.0 * acc_ref[...] + cn_ref[...]
+                      ).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -77,8 +84,12 @@ def cdist_pallas(
     cp = (c.astype(jnp.float32) if (np_, dp) == (n, d) else
           jnp.zeros((np_, dp), jnp.float32).at[:n, :d].set(
               c.astype(jnp.float32)))
-    xn = jnp.sum(xp * xp, axis=1)
-    cn = jnp.sum(cp * cp, axis=1)
+    # 2-D norm operands: a (bm, 1) column block and a lane-dense (1, bn)
+    # row block.  1-D (bm,)/(bn,) blocks do not match the layout XLA gives a
+    # long f32 vector on TPU, and Mosaic refuses them once m or n spans
+    # more than one block.
+    xn = jnp.sum(xp * xp, axis=1, keepdims=True)
+    cn = jnp.sum(cp * cp, axis=1)[None, :]
     k_steps = dp // bk
 
     out = pl.pallas_call(
@@ -87,13 +98,13 @@ def cdist_pallas(
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bn, bk), lambda i, j, k: (j, k)),
-            pl.BlockSpec((bm,), lambda i, j, k: (i,)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
+            pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
+            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xp, cp, xn, cn)

@@ -33,17 +33,40 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import TPUCompilerParams
+from repro.kernels.bid_top2 import merge_top2, top2_operands, top2_out_shape
+from repro.kernels.cdist import dot_t
 
-_NEG = -1e30
+_LANE = 128
+
+
+def row_table(x: jnp.ndarray) -> jnp.ndarray:
+    """(n, d) -> (n, 1, dp) float32 source table for the row DMAs.
+
+    A TPU DMA moves whole tiles: a single row of a 2-D (8, 128)-tiled array
+    is a slice the compiler refuses (the sublane dim is tiled by 8), and a
+    row narrower than the 128-lane tile is refused too.  Giving every row
+    its own unit sublane dim and zero-padding d up to a lane multiple ``dp``
+    makes each row one aligned (1, dp) slab.  The zero lanes change no dot
+    product or norm.
+    """
+    n, d = x.shape
+    dp = _rup(d, _LANE)
+    xf = x.astype(jnp.float32)
+    if dp != d:
+        xf = jnp.pad(xf, ((0, 0), (0, dp - d)))
+    return xf.reshape(n, 1, dp)
+
+
+def _copy(idx_ref, x_ref, rows, sems, slot, blk, bm, r):
+    return pltpu.make_async_copy(x_ref.at[idx_ref[blk * bm + r]],
+                                 rows.at[slot, r], sems.at[slot])
 
 
 def _issue_block(idx_ref, x_ref, rows, sems, slot, blk, bm):
     """Start the per-row HBM->VMEM copies for row block ``blk`` into ``slot``."""
 
     def row(r, _):
-        src = x_ref.at[idx_ref[blk * bm + r]]
-        pltpu.make_async_copy(src, rows.at[slot, r], sems.at[slot, r]).start()
+        _copy(idx_ref, x_ref, rows, sems, slot, blk, bm, r).start()
         return 0
 
     jax.lax.fori_loop(0, bm, row, 0)
@@ -53,12 +76,36 @@ def _wait_block(idx_ref, x_ref, rows, sems, slot, blk, bm):
     """Block until every row of ``blk`` has landed in ``slot``."""
 
     def row(r, _):
-        pltpu.make_async_copy(
-            x_ref.at[idx_ref[blk * bm + r]], rows.at[slot, r],
-            sems.at[slot, r]).wait()
+        _copy(idx_ref, x_ref, rows, sems, slot, blk, bm, r).wait()
         return 0
 
     jax.lax.fori_loop(0, bm, row, 0)
+
+
+def _landed(rows, slot):
+    """The (bm, dp) row block held in ring ``slot``."""
+    _, bm, _, dp = rows.shape
+    return rows[slot].reshape(bm, dp)
+
+
+def _ring(bm, dp):
+    """The 2-slot VMEM ring of (1, dp) row slabs and one DMA semaphore per
+    slot: every row copy of a block signals its slot's semaphore, and the
+    wait loop takes one row's worth per copy.  (A semaphore per row would
+    outgrow the chip's semaphore memory at bm = 256.)"""
+    return [pltpu.VMEM((2, bm, 1, dp), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,))]
+
+
+def _pad_idx(idx, n, mp):
+    idx_p = jnp.clip(idx.astype(jnp.int32), 0, n - 1)
+    m = idx.shape[0]
+    if mp > m:
+        idx_p = jnp.concatenate([idx_p, jnp.zeros((mp - m,), jnp.int32)])
+    return idx_p
+
+
+_HBM = pl.BlockSpec(memory_space=pltpu.HBM)
 
 
 # ---------------------------------------------------------------------------
@@ -80,45 +127,45 @@ def _gather_kernel(idx_ref, x_ref, o_ref, rows, sems, *, bm):
         _issue_block(idx_ref, x_ref, rows, sems, (j + 1) % 2, j + 1, bm)
 
     _wait_block(idx_ref, x_ref, rows, sems, j % 2, j, bm)
-    o_ref[...] = rows[j % 2]
+    o_ref[...] = _landed(rows, j % 2)
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "interpret"))
+@functools.partial(jax.jit, static_argnames=("d", "bm", "interpret"))
 def gather_rows_pallas(
-    x: jnp.ndarray,
+    table: jnp.ndarray,
     idx: jnp.ndarray,
     *,
+    d: int,
     bm: int = 256,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """``x[idx]`` with double-buffered DMA: (n, d), (m,) -> (m, d) float32.
+    """``x[idx]`` with double-buffered DMA: (n, 1, dp) :func:`row_table`,
+    (m,) -> (m, d) float32.
 
+    Takes the prepared table so a caller that gathers from the same ``x``
+    many times (the streaming core, once per chunk) lays it out once.
     Out-of-range indices are clipped (the streaming core clamps sentinels
     itself and masks their values downstream).
     """
-    n, d = x.shape
+    n, _, dp = table.shape
     m = idx.shape[0]
     bm = min(bm, _rup(m, 8))
     mp = _rup(m, bm)
-    idx_p = jnp.clip(idx.astype(jnp.int32), 0, n - 1)
-    if mp > m:
-        idx_p = jnp.concatenate([idx_p, jnp.zeros((mp - m,), jnp.int32)])
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(mp // bm,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec((bm, d), lambda j, idx_ref: (j, 0)),
-        scratch_shapes=[pltpu.VMEM((2, bm, d), jnp.float32),
-                        pltpu.SemaphoreType.DMA((2, bm))],
+        in_specs=[_HBM],
+        out_specs=pl.BlockSpec((bm, dp), lambda j, idx_ref: (j, 0)),
+        scratch_shapes=_ring(bm, dp),
     )
     out = pl.pallas_call(
         functools.partial(_gather_kernel, bm=bm),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((mp, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((mp, dp), jnp.float32),
         interpret=interpret,
-    )(idx_p, x.astype(jnp.float32))
-    return out[:m]
+    )(_pad_idx(idx, n, mp), table)
+    return out[:m, :d]
 
 
 # ---------------------------------------------------------------------------
@@ -147,28 +194,8 @@ def _bid_gather_kernel(idx_ref, x_ref, c_ref, cn_ref, p_ref,
         def _prefetch():
             _issue_block(idx_ref, x_ref, rows, sems, (i + 1) % 2, i + 1, bm)
 
-        v1_ref[...] = jnp.full_like(v1_ref, _NEG)
-        j1_ref[...] = jnp.zeros_like(j1_ref)
-        v2_ref[...] = jnp.full_like(v2_ref, _NEG)
-
-    vals = jax.lax.dot_general(
-        rows[i % 2], c_ref[...],
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    vals = -2.0 * vals + (cn_ref[...] - p_ref[...])[None, :]
-
-    col = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 1)
-    t_v1 = jnp.max(vals, axis=1)
-    t_j1 = jnp.min(jnp.where(vals >= t_v1[:, None], col, bn), axis=1)
-    t_v2 = jnp.max(jnp.where(col == t_j1[:, None], _NEG, vals), axis=1)
-    t_j1 = t_j1 + j * bn
-
-    r_v1, r_j1, r_v2 = v1_ref[...], j1_ref[...], v2_ref[...]
-    take = t_v1 > r_v1
-    v1_ref[...] = jnp.where(take, t_v1, r_v1)
-    j1_ref[...] = jnp.where(take, t_j1, r_j1)
-    v2_ref[...] = jnp.maximum(jnp.minimum(t_v1, r_v1),
-                              jnp.maximum(t_v2, r_v2))
+    merge_top2(dot_t(_landed(rows, i % 2), c_ref[...]), cn_ref, p_ref,
+               v1_ref, j1_ref, v2_ref, j=j, bn=bn)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
@@ -190,44 +217,32 @@ def bid_top2_gather_pallas(
     assert d == d2, (x.shape, c.shape)
     bm, bn = min(bm, _rup(m, 8)), min(bn, _rup(k, 128))
     mp, kp = _rup(m, bm), _rup(k, bn)
-    idx_p = jnp.clip(idx.astype(jnp.int32), 0, n - 1)
-    if mp > m:
-        idx_p = jnp.concatenate([idx_p, jnp.zeros((mp - m,), jnp.int32)])
-    cp = jnp.zeros((kp, d), jnp.float32).at[:k].set(c.astype(jnp.float32))
-    cn = jnp.sum(cp * cp, axis=1)
-    pp = jnp.full((kp,), -_NEG, jnp.float32).at[:k].set(
-        prices.astype(jnp.float32))
+    table = row_table(x)
+    dp = table.shape[-1]
+    cp, cn, pp = top2_operands(jnp.pad(c, ((0, 0), (0, dp - d))), prices, kp)
 
+    row = pl.BlockSpec((bm, 1), lambda i, j, idx_ref: (i, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(mp // bm, kp // bn),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec((bn, d), lambda i, j, idx_ref: (j, 0)),
-            pl.BlockSpec((bn,), lambda i, j, idx_ref: (j,)),
-            pl.BlockSpec((bn,), lambda i, j, idx_ref: (j,)),
+            _HBM,
+            pl.BlockSpec((bn, dp), lambda i, j, idx_ref: (j, 0)),
+            pl.BlockSpec((1, bn), lambda i, j, idx_ref: (0, j)),
+            pl.BlockSpec((1, bn), lambda i, j, idx_ref: (0, j)),
         ],
-        out_specs=[
-            pl.BlockSpec((bm,), lambda i, j, idx_ref: (i,)),
-            pl.BlockSpec((bm,), lambda i, j, idx_ref: (i,)),
-            pl.BlockSpec((bm,), lambda i, j, idx_ref: (i,)),
-        ],
-        scratch_shapes=[pltpu.VMEM((2, bm, d), jnp.float32),
-                        pltpu.SemaphoreType.DMA((2, bm))],
+        out_specs=[row, row, row],
+        scratch_shapes=_ring(bm, dp),
     )
     v1, j1, v2 = pl.pallas_call(
         functools.partial(_bid_gather_kernel, bm=bm, bn=bn),
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((mp,), jnp.float32),
-            jax.ShapeDtypeStruct((mp,), jnp.int32),
-            jax.ShapeDtypeStruct((mp,), jnp.float32),
-        ],
-        compiler_params=TPUCompilerParams(
+        out_shape=top2_out_shape(mp),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(idx_p, x.astype(jnp.float32), cp, cn, pp)
-    return v1[:m], j1[:m], v2[:m]
+    )(_pad_idx(idx, n, mp), table, cp, cn, pp)
+    return v1[:m, 0], j1[:m, 0], v2[:m, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -254,14 +269,10 @@ def _cdist_gather_kernel(idx_ref, x_ref, c_ref, cn_ref, o_ref, rows, sems,
         def _prefetch():
             _issue_block(idx_ref, x_ref, rows, sems, (i + 1) % 2, i + 1, bm)
 
-    xb = rows[i % 2]
-    dots = jax.lax.dot_general(
-        xb, c_ref[...],
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    xn = jnp.sum(xb * xb, axis=1)
-    o_ref[...] = (xn[:, None] - 2.0 * dots + cn_ref[...][None, :]
-                  ).astype(o_ref.dtype)
+    xb = _landed(rows, i % 2)
+    dots = dot_t(xb, c_ref[...])
+    xn = jnp.sum(xb * xb, axis=1, keepdims=True)
+    o_ref[...] = (xn - 2.0 * dots + cn_ref[...]).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -284,32 +295,31 @@ def cdist_gather_pallas(
     assert d == d2, (x.shape, c.shape)
     bm, bn = min(bm, _rup(m, 8)), min(bn, _rup(nc, 128))
     mp, ncp = _rup(m, bm), _rup(nc, bn)
-    idx_p = jnp.clip(idx.astype(jnp.int32), 0, n - 1)
-    if mp > m:
-        idx_p = jnp.concatenate([idx_p, jnp.zeros((mp - m,), jnp.int32)])
-    cp = jnp.zeros((ncp, d), jnp.float32).at[:nc].set(c.astype(jnp.float32))
-    cn = jnp.sum(cp * cp, axis=1)
+    table = row_table(x)
+    dp = table.shape[-1]
+    cp = jnp.zeros((ncp, dp), jnp.float32).at[:nc, :d].set(
+        c.astype(jnp.float32))
+    cn = jnp.sum(cp * cp, axis=1)[None, :]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(mp // bm, ncp // bn),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec((bn, d), lambda i, j, idx_ref: (j, 0)),
-            pl.BlockSpec((bn,), lambda i, j, idx_ref: (j,)),
+            _HBM,
+            pl.BlockSpec((bn, dp), lambda i, j, idx_ref: (j, 0)),
+            pl.BlockSpec((1, bn), lambda i, j, idx_ref: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, idx_ref: (i, j)),
-        scratch_shapes=[pltpu.VMEM((2, bm, d), jnp.float32),
-                        pltpu.SemaphoreType.DMA((2, bm))],
+        scratch_shapes=_ring(bm, dp),
     )
     out = pl.pallas_call(
         functools.partial(_cdist_gather_kernel, bm=bm),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((mp, ncp), out_dtype),
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(idx_p, x.astype(jnp.float32), cp, cn)
+    )(_pad_idx(idx, n, mp), table, cp, cn)
     return out[:m, :nc]
 
 

@@ -1,8 +1,8 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On TPU the kernels run compiled; on CPU (this container) they run in
-``interpret=True`` mode, which executes the kernel body in Python -- correct
-but slow, so the wrappers fall back to the jnp reference for *large* CPU
+On TPU the kernels run compiled (and nothing falls back: a kernel the
+TPU compiler refuses fails the call); on CPU they run in ``interpret=True``
+mode, which executes the kernel body as jnp ops -- correct but slow, so the wrappers fall back to the jnp reference for *large* CPU
 inputs while tests pin ``force="pallas"`` to exercise the kernel path.
 
 Both dispatchers accept leading *chunk*/stack dims:
@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from repro.kernels.bid_top2 import bid_top2_pallas
 from repro.kernels.cdist import cdist_pallas
 from repro.kernels.gather import (bid_top2_gather_pallas, cdist_gather_pallas,
-                                  gather_rows_pallas)
+                                  gather_rows_pallas, row_table)
 from repro.kernels.ref import bid_top2_ref, cdist_ref
 
 _CPU_INTERPRET_BUDGET = 1 << 22  # elements; above this CPU uses the ref
@@ -79,21 +79,32 @@ def gather_path(force: str | None = None) -> str:
     return "ref"
 
 
-def gather_rows(x: jnp.ndarray, idx: jnp.ndarray, *,
-                force: str | None = None, **block_kw) -> jnp.ndarray:
-    """``x[idx]`` as float32: (n, d), (m,) -> (m, d).
+def row_gatherer(x: jnp.ndarray, *, force: str | None = None, **block_kw):
+    """``idx -> x[idx]`` as float32, with ``x`` laid out for the gather once.
 
     On TPU this is the double-buffered DMA gather
     (:func:`repro.kernels.gather.gather_rows_pallas`) -- the next block's
-    HBM row movement overlaps the current block's copy-out; on CPU it is the
-    plain jnp take (bit-identical, so the streaming core's parity contract
-    is path-independent).  Out-of-range indices are clipped on the kernel
+    HBM row movement overlaps the current block's copy-out -- reading the
+    lane-padded :func:`repro.kernels.gather.row_table` built here, outside
+    any loop the caller runs the gather in (XLA does not hoist that padded
+    copy out of a scan body by itself).  On CPU it is the plain jnp take
+    (bit-identical, so the streaming core's parity contract is
+    path-independent).  Out-of-range indices are clipped on the kernel
     path; callers clamp before the ref path.
     """
     path = gather_path(force)
     if path == "ref":
-        return x[idx].astype(jnp.float32)
-    return gather_rows_pallas(x, idx, interpret=path != "pallas", **block_kw)
+        return lambda idx: x[idx].astype(jnp.float32)
+    table = row_table(x)
+    return lambda idx: gather_rows_pallas(
+        table, idx, d=x.shape[1], interpret=path != "pallas", **block_kw)
+
+
+def gather_rows(x: jnp.ndarray, idx: jnp.ndarray, *,
+                force: str | None = None, **block_kw) -> jnp.ndarray:
+    """``x[idx]`` as float32: (n, d), (m,) -> (m, d); one-off
+    :func:`row_gatherer`."""
+    return row_gatherer(x, force=force, **block_kw)(idx)
 
 
 def cdist(x: jnp.ndarray, c: jnp.ndarray, *, idx: jnp.ndarray | None = None,

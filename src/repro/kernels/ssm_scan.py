@@ -22,7 +22,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.compat import TPUCompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _ssm_chunk_kernel(dt_ref, b_ref, c_ref, x_ref, a_ref, h0_ref,
@@ -71,7 +71,7 @@ def ssm_scan_chunk_pallas(dt, b_in, c_out, x_in, a_mat, h0, *,
             jax.ShapeDtypeStruct((c, bsz, di), jnp.float32),
             jax.ShapeDtypeStruct((bsz, di, ds), jnp.float32),
         ],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(dt.astype(jnp.float32), b_in.astype(jnp.float32),

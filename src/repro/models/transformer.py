@@ -15,9 +15,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-from repro.compat import shard_map
 
 from repro.models.config import LayerSpec, ModelConfig
 from repro.models import layers as L

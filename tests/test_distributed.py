@@ -118,7 +118,7 @@ def test_ef_compression_error_bounded():
     out = _run("""
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import Mesh, PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
         from repro.train.compression import _compress_leaf
         mesh = Mesh(np.array(jax.devices()).reshape(8,), ("data",))
         rng = np.random.default_rng(0)

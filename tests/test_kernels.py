@@ -1,6 +1,9 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret mode
-(the kernel body executes in Python on CPU; on TPU the same BlockSpecs run
-compiled)."""
+"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret mode.
+
+The kernel body executes as jnp ops on CPU, so these pin what each kernel
+computes; whether the TPU compiler accepts the same BlockSpecs and DMAs is
+checked separately, against a described v5e, in tests/test_tpu_compile.py.
+"""
 
 import numpy as np
 import jax.numpy as jnp
@@ -66,8 +69,7 @@ def test_bid_top2_property(m, n, d, seed):
 
 
 # --- streaming-chunk gather kernels (double-buffered DMA ring) -------------
-# interpret=True executes the same make_async_copy ring in Python on CPU;
-# on TPU the identical BlockSpecs run compiled.
+# interpret=True executes the same make_async_copy ring on CPU.
 
 GATHER_SHAPES = [(1, 1, 1), (200, 37, 8), (1000, 256, 32), (513, 300, 130)]
 
@@ -147,6 +149,46 @@ def test_gather_rows_property(n, m, d, seed):
     got = np.asarray(gather_rows(jnp.asarray(x), jnp.asarray(idx),
                                  force="pallas", bm=32))
     np.testing.assert_array_equal(got, x[idx])
+
+
+# Several row AND column blocks (m, k > 1024): the shape class whose 1-D
+# norm/price/top-2 blocks the TPU compiler refused, and where the gather
+# ring cycles both slots more than once.
+MULTI_M, MULTI_K, MULTI_D = 1100, 1030, 12
+
+
+@pytest.mark.parametrize("kernel", ["cdist", "bid_top2", "gather_rows",
+                                    "cdist_gather", "bid_top2_gather"])
+def test_multi_block_parity(kernel, rng):
+    from repro.kernels.ops import gather_rows
+    x = rng.normal(size=(3 * MULTI_M, MULTI_D)).astype(np.float32)
+    c = rng.normal(size=(MULTI_K, MULTI_D)).astype(np.float32)
+    p = rng.normal(size=(MULTI_K,)).astype(np.float32)
+    idx = rng.integers(0, x.shape[0], size=(MULTI_M,)).astype(np.int32)
+    xj, cj, pj, ij = (jnp.asarray(a) for a in (x, c, p, idx))
+    rows = x[idx]
+    if kernel == "gather_rows":
+        np.testing.assert_array_equal(
+            np.asarray(gather_rows(xj, ij, force="pallas")), rows)
+        return
+    if kernel in ("cdist", "cdist_gather"):
+        got = cdist(jnp.asarray(rows), cj, force="pallas") \
+            if kernel == "cdist" else cdist(xj, cj, idx=ij, force="pallas")
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(cdist_ref(jnp.asarray(rows), cj)),
+            rtol=2e-3, atol=2e-3)
+        return
+    got = bid_top2(jnp.asarray(rows), cj, pj, force="pallas") \
+        if kernel == "bid_top2" else bid_top2(xj, cj, pj, idx=ij,
+                                              force="pallas")
+    gv1, gj1, gv2 = (np.asarray(a) for a in got)
+    rv1, _, rv2 = (np.asarray(a) for a in bid_top2_ref(
+        jnp.asarray(rows), cj, pj))
+    np.testing.assert_allclose(gv1, rv1, rtol=1e-3, atol=1e-3)
+    np.testing.assert_allclose(gv2, rv2, rtol=1e-3, atol=1e-3)
+    vals = -2 * rows @ c.T + (c * c).sum(1)[None] - p[None]
+    np.testing.assert_allclose(vals[np.arange(MULTI_M), gj1], rv1,
+                               rtol=1e-3, atol=1e-3)
 
 
 @pytest.mark.parametrize("s,di,ds,chunk", [(32, 64, 8, 8), (48, 128, 16, 16),
