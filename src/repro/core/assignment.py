@@ -115,19 +115,20 @@ def _top2_batched(values: jnp.ndarray):
 def _auction_phase(top2_fn, prices: jnp.ndarray, eps: jnp.ndarray,
                    max_rounds: int, fixed_rounds: int = 0,
                    skip: jnp.ndarray | None = None,
-                   seed_top2=None,
-                   return_rounds: bool = False,
-                   ) -> tuple[jnp.ndarray, jnp.ndarray]:
+                   seed_top2=None, resident=None,
+                   ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One epsilon phase of batched Jacobi forward auction (maximization).
 
     ``top2_fn(prices)`` returns the per-row ``(v1, j1, v2)`` of the reduced
     value matrix ``value[b, i, j] = cost[b, i, j] - prices[b, j]``, each
     (B, n) -- the *bidding round reduction*, pluggable so the dense path and
     the fused matrix-free kernel path share one engine.  Prices/eps are
-    (B, n) / (B,).  Returns (row_to_col, prices).  All rows start unassigned;
-    prices persist across phases (standard eps-scaling).  A fully assigned
-    instance places no bids, so the round update is a no-op for it while the
-    rest of the batch keeps iterating (per-instance convergence masking).
+    (B, n) / (B,).  Returns (row_to_col, prices, rounds): ``rounds`` is the
+    phase's executed round count (the ``it`` counter the loop carries; the
+    solver telemetry source).  All rows start unassigned; prices persist
+    across phases (standard eps-scaling).  A fully assigned instance places
+    no bids, so the round update is a no-op for it while the rest of the
+    batch keeps iterating (per-instance convergence masking).
 
     ``skip`` ((B,) bool) marks instances that sit this phase out entirely:
     their rows start pre-assigned (identity), so by the masking above they
@@ -142,21 +143,30 @@ def _auction_phase(top2_fn, prices: jnp.ndarray, eps: jnp.ndarray,
     makes the probe free (it becomes round one).  The values are what the
     round would compute itself, so results are unchanged.
 
-    ``return_rounds=True`` additionally returns the phase's executed round
-    count (the ``it`` counter the loop already carries) -- the solver
-    telemetry source, free because the value exists either way.
+    ``resident`` (from :func:`repro.kernels.ops.resident_auction`) runs the
+    round loop in one Pallas kernel on the VMEM-resident cost block instead;
+    the round is the same arithmetic, so the results and the round count
+    are bitwise those of the loop below (a seeded first round runs there
+    even where every row is pre-assigned, as it does here).
     """
     B, n = prices.shape
-    rows = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32), (B, n))
     cols = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32), (B, n))
+    assign0 = jnp.full((B, n), -1, jnp.int32)
+    if skip is not None:
+        # pre-assigned identity: no bids, a fixed point of the round update
+        assign0 = jnp.where(skip[:, None], cols, assign0)
+    if resident is not None:
+        return resident(prices, eps, assign0, max_rounds,
+                        int(seed_top2 is not None))
+    rows = cols
     barange = jnp.arange(B)[:, None]
 
     def cond(state):
-        assign, _owner, _prices, it = state
+        assign, _prices, it = state
         return jnp.logical_and(jnp.any(assign < 0), it < max_rounds)
 
     def body_with(state, top2):
-        assign, owner, prices, it = state
+        assign, prices, it = state
         unassigned = assign < 0
         v1, j1, v2 = top2
         # Bid: raise the price of the favourite object past the point of
@@ -184,19 +194,13 @@ def _auction_phase(top2_fn, prices: jnp.ndarray, eps: jnp.ndarray,
         # Winners take their objects at the winning bid.
         winner_safe = jnp.where(got_bid, winner, n)
         assign = assign.at[barange, winner_safe].set(cols, mode="drop")
-        owner = jnp.where(got_bid, winner, owner)
         prices = jnp.where(got_bid, best, prices)
-        return assign, owner, prices, it + 1
+        return assign, prices, it + 1
 
     def body(state):
-        return body_with(state, top2_fn(state[2]))
+        return body_with(state, top2_fn(state[1]))
 
-    assign0 = jnp.full((B, n), -1, jnp.int32)
-    if skip is not None:
-        # pre-assigned identity: no bids, a fixed point of the round update
-        assign0 = jnp.where(skip[:, None], cols, assign0)
-    owner0 = jnp.full((B, n), -1, jnp.int32)
-    state0 = (assign0, owner0, prices, jnp.int32(0))
+    state0 = (assign0, prices, jnp.int32(0))
     rounds = fixed_rounds
     if seed_top2 is not None:
         # round one, with the caller's precomputed reduction (same values
@@ -207,13 +211,9 @@ def _auction_phase(top2_fn, prices: jnp.ndarray, eps: jnp.ndarray,
         # converged state is a fixed point of body (no bids -> no updates)
         def scan_body(state, _):
             return body(state), None
-        (assign, _owner, prices, it), _ = jax.lax.scan(
-            scan_body, state0, None, length=rounds)
-    else:
-        assign, _owner, prices, it = jax.lax.while_loop(cond, body, state0)
-    if return_rounds:
-        return assign, prices, it
-    return assign, prices
+        state, _ = jax.lax.scan(scan_body, state0, None, length=rounds)
+        return state
+    return jax.lax.while_loop(cond, body, state0)
 
 
 def _eps_schedule(span: jnp.ndarray, n: int, config: AuctionConfig):
@@ -231,9 +231,12 @@ def _eps_schedule(span: jnp.ndarray, n: int, config: AuctionConfig):
 def _run_phases(top2_fn, eps_sched: jnp.ndarray, n: int,
                 config: AuctionConfig,
                 prices0: jnp.ndarray | None = None,
-                return_stats: bool = False,
+                return_stats: bool = False, resident=None,
                 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Run the eps-scaling schedule; returns (assignment, final prices).
+
+    ``resident`` is the phase implementation on the VMEM-resident kernel
+    (see :func:`_auction_phase`); ``None`` runs the XLA round loop.
 
     ``return_stats=True`` appends a solver telemetry pytree -- the numbers
     the loops already compute, surfaced instead of discarded, so the stats
@@ -277,32 +280,29 @@ def _run_phases(top2_fn, eps_sched: jnp.ndarray, n: int,
     n_phases = eps_sched.shape[0]
     max_rounds = config.max_rounds or (50 * n + 1000)
 
-    def phase(prices, eps):
-        assign, prices = _auction_phase(top2_fn, prices, eps, max_rounds,
-                                        config.fixed_rounds)
-        return prices, assign
+    def run_phase(prices, eps, skip=None, seed_top2=None):
+        return _auction_phase(top2_fn, prices, eps, max_rounds,
+                              config.fixed_rounds, skip=skip,
+                              seed_top2=seed_top2, resident=resident)
 
-    def phase_stats(prices, eps):
-        assign, prices, it = _auction_phase(top2_fn, prices, eps, max_rounds,
-                                            config.fixed_rounds,
-                                            return_rounds=True)
-        return prices, (assign, it)
+    def done(assign, prices, stats):
+        # Safety net: if the round cap was hit, columns may be unassigned;
+        # patch them greedily so the result is always a permutation.
+        assign = _repair_permutation(assign)
+        return (assign, prices, stats) if return_stats else (assign, prices)
 
     if prices0 is None:
-        if not return_stats:
-            prices, assigns = jax.lax.scan(
-                phase, jnp.zeros((B, n), jnp.float32), eps_sched)
-            # Safety net: if the round cap was hit, columns may be
-            # unassigned; patch them greedily so the result is always a
-            # permutation.
-            return _repair_permutation(assigns[-1]), prices
+        def phase(prices, eps):
+            assign, prices, it = run_phase(prices, eps)
+            return prices, (assign, it)
+
         prices, (assigns, rounds) = jax.lax.scan(
-            phase_stats, jnp.zeros((B, n), jnp.float32), eps_sched)
+            phase, jnp.zeros((B, n), jnp.float32), eps_sched)
         stats = {"rounds": rounds.astype(jnp.int32), "eps": eps_sched,
                  "warm": jnp.zeros((B,), bool),
                  "reentry": jnp.zeros((B,), jnp.float32),
                  "skipped": jnp.zeros((n_phases, B), bool)}
-        return _repair_permutation(assigns[-1]), prices, stats
+        return done(assigns[-1], prices, stats)
 
     prices0 = prices0.astype(jnp.float32)
     is_warm = jnp.any(prices0 != 0.0, axis=1)          # (B,) per instance
@@ -326,25 +326,14 @@ def _run_phases(top2_fn, eps_sched: jnp.ndarray, n: int,
         # legacy fixed shortcut: warm instances skip all but the last phase
         probe = None
         reentry = jnp.full((B,), -jnp.inf)
+    skipped = jnp.logical_and(
+        is_warm[None, :],
+        jnp.logical_and(jnp.logical_not(is_last)[:, None],
+                        eps_sched > reentry[None, :]))
 
     def phase_p(prices, inp):
-        eps, last = inp
-        skip = jnp.logical_and(
-            is_warm,
-            jnp.logical_and(jnp.logical_not(last), eps > reentry))
-        assign, prices = _auction_phase(
-            top2_fn, prices, eps, max_rounds, config.fixed_rounds,
-            skip=skip)
-        return prices, assign
-
-    def phase_p_stats(prices, inp):
-        eps, last = inp
-        skip = jnp.logical_and(
-            is_warm,
-            jnp.logical_and(jnp.logical_not(last), eps > reentry))
-        assign, prices, it = _auction_phase(
-            top2_fn, prices, eps, max_rounds, config.fixed_rounds,
-            skip=skip, return_rounds=True)
+        eps, skip = inp
+        assign, prices, it = run_phase(prices, eps, skip=skip)
         return prices, (assign, it)
 
     # Phase 1 unrolled so it can consume the probe reduction (every instance
@@ -354,34 +343,17 @@ def _run_phases(top2_fn, eps_sched: jnp.ndarray, n: int,
     # warm at equilibrium, only the final phase live -- costs the same as
     # the old jump-straight-to-the-last-phase shortcut (measured slightly
     # less: a branchless scan of empty phases beats a lax.cond dispatch).
-    skip0 = jnp.logical_and(
-        is_warm, jnp.logical_and(jnp.logical_not(is_last[0]),
-                                 eps_sched[0] > reentry))
-    if not return_stats:
-        assign, prices = _auction_phase(
-            top2_fn, prices0, eps_sched[0], max_rounds, config.fixed_rounds,
-            skip=skip0, seed_top2=probe)
-        if n_phases > 1:
-            prices, assigns = jax.lax.scan(
-                phase_p, prices, (eps_sched[1:], is_last[1:]))
-            assign = assigns[-1]
-        return _repair_permutation(assign), prices
-    assign, prices, it0 = _auction_phase(
-        top2_fn, prices0, eps_sched[0], max_rounds, config.fixed_rounds,
-        skip=skip0, seed_top2=probe, return_rounds=True)
+    assign, prices, it0 = run_phase(prices0, eps_sched[0], skip=skipped[0],
+                                    seed_top2=probe)
     rounds = it0[None]
     if n_phases > 1:
         prices, (assigns, its) = jax.lax.scan(
-            phase_p_stats, prices, (eps_sched[1:], is_last[1:]))
+            phase_p, prices, (eps_sched[1:], skipped[1:]))
         assign = assigns[-1]
         rounds = jnp.concatenate([rounds, its])
-    skipped = jnp.logical_and(
-        is_warm[None, :],
-        jnp.logical_and(jnp.logical_not(is_last)[:, None],
-                        eps_sched > reentry[None, :]))
     stats = {"rounds": rounds.astype(jnp.int32), "eps": eps_sched,
              "warm": is_warm, "reentry": reentry, "skipped": skipped}
-    return _repair_permutation(assign), prices, stats
+    return done(assign, prices, stats)
 
 
 def _solve_stack(cost: jnp.ndarray, config: AuctionConfig,
@@ -397,8 +369,13 @@ def _solve_stack(cost: jnp.ndarray, config: AuctionConfig,
     def top2_fn(prices):
         return _top2_batched(cost - prices[:, None, :])
 
+    resident = None
+    if not config.fixed_rounds:  # the dry-run's scan keeps the XLA loop
+        from repro.kernels import ops
+        resident = ops.resident_auction(cost)
     return _run_phases(top2_fn, _eps_schedule(span, n, config), n, config,
-                       prices0, return_stats=return_stats)
+                       prices0, return_stats=return_stats,
+                       resident=resident)
 
 
 def _zero_stats(B: int, config: AuctionConfig) -> dict:

@@ -1,6 +1,7 @@
 """Pallas TPU kernels for the compute hot-spots the paper optimizes:
-the object<->centroid cost matrix (Fact 1 fast path) and the auction
-bidding reduction.  ops.py holds the jit'd public wrappers, ref.py the
+the object<->centroid cost matrix (Fact 1 fast path), the auction
+bidding reduction and the dense auction's VMEM-resident round loop
+(auction.py).  ops.py holds the jit'd public wrappers, ref.py the
 pure-jnp oracles used by the allclose tests."""
 
 from repro.kernels.ops import bid_top2, cdist

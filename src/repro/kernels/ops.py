@@ -30,6 +30,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.auction import (auction_phase_pallas, block_bytes,
+                                   padded_cost)
 from repro.kernels.bid_top2 import bid_top2_pallas
 from repro.kernels.cdist import cdist_pallas
 from repro.kernels.gather import (bid_top2_gather_pallas, cdist_gather_pallas,
@@ -38,6 +40,12 @@ from repro.kernels.ref import bid_top2_ref, cdist_ref
 
 _CPU_INTERPRET_BUDGET = 1 << 22  # elements; above this CPU uses the ref
 _GATHER_FUSE_MAX_D = 512  # fused-gather kernels keep full rows in VMEM
+# Scoped VMEM the resident auction kernel asks for.  A phase holds the
+# instance's padded cost block twice (the pipeline prefetches the next
+# instance's) plus the round's temporaries, under one more block's worth
+# (measured: 3.6 MiB at n = 512, 11.5 MiB at n = 1024, compiled for a
+# v5e), so it engages while four blocks fit: n up to 1,408.
+_RESIDENT_VMEM_BYTES = 32 << 20
 
 
 def _backend() -> str:
@@ -58,6 +66,48 @@ def resolve_path(m: int, k: int, force: str | None = None) -> str:
     if force == "pallas" or m * k <= _CPU_INTERPRET_BUDGET:
         return "pallas-interpret"
     return "ref"
+
+
+def resident_auction_path(n: int, force: str | None = None) -> str:
+    """Which path an eps phase of the dense auction on (n, n) instances
+    takes: 'pallas' (TPU, four padded blocks fit the kernel's scoped VMEM),
+    'pallas-interpret' (forced on CPU) or 'xla' (the XLA round loop).
+
+    On CPU the default is the XLA loop: interpreting a thousand-round loop
+    has no fidelity value, and the results are bitwise the same.  Tests pin
+    ``force="pallas"`` to run the kernel in interpret mode.
+    """
+    if force == "xla":
+        return "xla"
+    if _backend() == "tpu":
+        if force == "pallas" or 4 * block_bytes(n) <= _RESIDENT_VMEM_BYTES:
+            return "pallas"
+        return "xla"
+    return "pallas-interpret" if force == "pallas" else "xla"
+
+
+def resident_auction(cost: jnp.ndarray, *, force: str | None = None):
+    """The dense auction's phase on the resident kernel, for a (B, n, n)
+    ``cost`` stack, or None where the XLA round loop runs (see
+    :func:`resident_auction_path`).
+
+    The phase takes ``(prices, eps, assign0, max_rounds, min_rounds)`` and
+    returns ``(assign, prices, rounds)``: the rounds are those of the
+    instance that ran longest, as the batched XLA loop counts them.
+    """
+    n = cost.shape[-1]
+    path = resident_auction_path(n, force)
+    if path == "xla":
+        return None
+    padded = padded_cost(cost)
+
+    def phase(prices, eps, assign0, max_rounds, min_rounds):
+        assign, prices, rounds = auction_phase_pallas(
+            padded, prices, eps, assign0, n=n, max_rounds=max_rounds,
+            min_rounds=min_rounds, vmem_limit_bytes=_RESIDENT_VMEM_BYTES,
+            interpret=path != "pallas")
+        return assign, prices, jnp.max(rounds)
+    return phase
 
 
 def gather_path(force: str | None = None) -> str:

@@ -17,10 +17,12 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.auction import auction_phase_pallas, padded_cost
 from repro.kernels.bid_top2 import bid_top2_pallas
 from repro.kernels.cdist import cdist_pallas
 from repro.kernels.gather import (bid_top2_gather_pallas, cdist_gather_pallas,
                                   gather_rows_pallas, row_table)
+from repro.kernels.ops import _RESIDENT_VMEM_BYTES
 
 WIDTHS = {"imagenet8": (8192, 192), "finance": (4096, 12)}
 
@@ -87,4 +89,16 @@ def test_kernel_compiles_for_v5e(kernel, width, one_chip):
 def test_stacked_bid_top2_compiles_for_v5e(G, m, d, one_chip):
     fn = jax.vmap(lambda x, c, p: bid_top2_pallas(x, c, p))
     shapes = [((G, m, d), F32), ((G, m, d), F32), ((G, m), F32)]
+    assert "tpu_custom_call" in _compiled_text(fn, one_chip, *shapes)
+
+
+# the resident auction phase: mnist's flat 512-wide LAP (one instance) and
+# a stack of 32-wide ones, one program per instance
+@pytest.mark.parametrize("B,n", [(1, 512), (32, 32)])
+def test_resident_auction_compiles_for_v5e(B, n, one_chip):
+    def fn(c, p, e, a):
+        return auction_phase_pallas(
+            padded_cost(c), p, e, a, n=n, max_rounds=50 * n + 1000,
+            vmem_limit_bytes=_RESIDENT_VMEM_BYTES)
+    shapes = [((B, n, n), F32), ((B, n), F32), ((B,), F32), ((B, n), I32)]
     assert "tpu_custom_call" in _compiled_text(fn, one_chip, *shapes)
